@@ -1,0 +1,12 @@
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import jax  # noqa: E402
+
+# CPU test runs compile small programs; keep them out of the persistent cache
+jax.config.update("jax_enable_compilation_cache", False)
