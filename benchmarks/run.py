@@ -17,7 +17,7 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
 
-    from . import kernel_micro, paper_figures, roofline, training_race
+    from . import kernel_micro, paper_figures, training_race
 
     benches = {
         "fig1": paper_figures.bench_fig1_transient,
@@ -29,7 +29,6 @@ def main() -> None:
         "fig12": paper_figures.bench_fig12_3cluster,
         "table2": training_race.bench_table2_accuracy,
         "kernels": kernel_micro.bench_kernels,
-        "roofline": roofline.bench_roofline,
     }
     selected = [s for s in args.only.split(",") if s] or list(benches)
 

@@ -54,6 +54,7 @@ try:
     import jax
     import jax.numpy as jnp
 
+    from repro import obs
     from repro.configs.base import FLConfig
     from repro.fl import LMTask, run_experiment
     from repro.fl.engine import _cached_fl_setup
@@ -108,24 +109,6 @@ UPDATE_RTOL = 1e-3
 LOSS_ATOL = 1e-3
 
 
-class CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling, summed from its
-    monitoring events; register an instance as a duration listener."""
-
-    EVENTS = (
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def __call__(self, event, secs, **_):
-        if event in self.EVENTS:
-            self.seconds += secs
-
-
 FAILED: list[str] = []
 
 
@@ -137,11 +120,11 @@ def check(ok: bool, what: str) -> None:
         FAILED.append(what)
 
 
-def phase(clock: CompileClock, name: str, fn, *args):
+def phase(name: str, fn, *args):
     print(f"[{name}]", flush=True)
-    t0, c0 = time.perf_counter(), clock.seconds
+    t0, c0 = time.perf_counter(), obs.compile_seconds()
     out = fn(*args)
-    wall, comp = time.perf_counter() - t0, clock.seconds - c0
+    wall, comp = time.perf_counter() - t0, obs.compile_seconds() - c0
     print(f"[{name}] wall_s={wall} compile_s={comp}", flush=True)
     return out
 
@@ -355,19 +338,18 @@ def main() -> None:
         sys.exit(f"chip_smoke: --four-chips needs 4 TPU chips, found {len(devices)}")
     print(f"device {dev.device_kind} x{len(devices)}, jax {jax.__version__}, "
           f"compile cache {cache_dir}", flush=True)
-    clock = CompileClock()
-    jax.monitoring.register_event_duration_secs_listener(clock)
+    obs.install()
 
     t0 = time.perf_counter()
     if args.four_chips:
-        phase(clock, "four chips: lane-sharded LM vs per-event",
+        phase("four chips: lane-sharded LM vs per-event",
               phase_four_chips, args.seed)
     else:
-        phase(clock, "A: classification, fused device stream", phase_a, args.seed)
-        task = phase(clock, "B: Mamba2-130M, fused engine", phase_b, args.seed)
-        phase(clock, "B: SSD kernel vs jnp forward", phase_b_ssd, args.seed, task)
-        phase(clock, "B: kernel vs jnp gradient", phase_b_grad, args.seed, task)
-        phase(clock, "B: scan engine vs Python loop", phase_b_events, args.seed)
+        phase("A: classification, fused device stream", phase_a, args.seed)
+        task = phase("B: Mamba2-130M, fused engine", phase_b, args.seed)
+        phase("B: SSD kernel vs jnp forward", phase_b_ssd, args.seed, task)
+        phase("B: kernel vs jnp gradient", phase_b_grad, args.seed, task)
+        phase("B: scan engine vs Python loop", phase_b_events, args.seed)
     print(f"total wall_s={time.perf_counter() - t0}", flush=True)
     if FAILED:
         sys.exit(f"chip_smoke: {len(FAILED)} check(s) failed: " + "; ".join(FAILED))

@@ -36,6 +36,7 @@ from typing import Any, Callable, Protocol
 
 import numpy as np
 
+from .. import obs
 from .queue_sim import (
     KIND_COMPLETE,
     KIND_FLIP,
@@ -363,7 +364,21 @@ def _run_scan(
     oracle); "device" generates it inside the compiled program
     (`engine_scan.make_fused_runner`) — zero host pre-simulation, and the
     only mode supporting ``cfg.adaptive`` sampling.
+
+    The call records the host span `obs.CALL` with the children
+    `obs.PREPARE` (validation, resolution, the block-size probe, the runner
+    memo and argument transfer), `obs.LAUNCH` (the runner call returning),
+    `obs.WAIT` (``block_until_ready``) and `obs.READBACK` (results to the
+    host); a call that builds a fused runner records its scope table
+    (`obs.record_scopes`) in `obs.SCOPE_TABLE` between the last two.
     """
+    with obs.span(obs.CALL), obs.phases(obs.PREPARE) as phase:
+        return _scan_call(w0, source, cfg, eval_fn, p, mu, phase,
+                          fedbuff_Z=fedbuff_Z)
+
+
+def _scan_call(w0, source, cfg, eval_fn, p, mu, phase, *, fedbuff_Z=0):
+    """`_run_scan`'s body; ``phase(name)`` moves to its next host span."""
     import jax
     import jax.numpy as jnp
 
@@ -479,6 +494,7 @@ def _run_scan(
                     "the checkpointed fused engine supports the default "
                     "update w - scale*g with fedbuff_Z=0"
                 )
+            phase(obs.LAUNCH)
             w, evals, ck_extras = run_checkpointed(
                 _device_grad_fn(source), cfg.n, cfg.C, cfg.T,
                 w0=w0_dev, mu=mu, p0=p, key=jax.random.PRNGKey(cfg.seed),
@@ -489,7 +505,9 @@ def _run_scan(
                 block_size=int(block_size), snapshot_dtype=cfg.snapshot_dtype,
                 fault=faults, guard=guard, serving=serving, resume=cfg.resume,
             )
+            phase(obs.WAIT)
             w = jax.block_until_ready(w)
+            phase(obs.READBACK)
             # the chunked driver keeps no per-step clock (only the final t)
             trace = TraceRecord(
                 steps=np.arange(cfg.T), times=np.full(cfg.T, np.nan)
@@ -504,6 +522,7 @@ def _run_scan(
                 ]
                 trace.eval_values = [float(v) for v in np.asarray(evals)]
             return w, trace
+        builds = obs.counters["runner_builds"]
         runner = jit_fused_runner(
             _device_grad_fn(source),
             cfg.n,
@@ -530,11 +549,16 @@ def _run_scan(
         )
         run_mu = mu if classes is None else class_mu
         run_p = p if classes is None else class_p
-        w, evals, extras = runner(
-            w0_dev, jnp.asarray(run_mu), jnp.asarray(run_p),
-            jax.random.PRNGKey(cfg.seed), cfg.eta,
-        )
+        args = (w0_dev, jnp.asarray(run_mu), jnp.asarray(run_p),
+                jax.random.PRNGKey(cfg.seed), cfg.eta)
+        phase(obs.LAUNCH)
+        w, evals, extras = runner(*args)
+        phase(obs.WAIT)
         w = jax.block_until_ready(w)
+        if obs.counters["runner_builds"] != builds:
+            phase(obs.SCOPE_TABLE)
+            obs.record_scopes(runner, *args)
+        phase(obs.READBACK)
         if classes is not None:
             extras = _expand_class_extras(extras, classes)
         times = (
@@ -623,6 +647,7 @@ def _run_scan(
                     raise ValueError(
                         "checkpointing does not compose with lane sharding"
                     )
+                phase(obs.LAUNCH)
                 out = run_checkpointed_host_blocked(
                     _device_grad_fn(source), cfg.C, int(block_size),
                     w0_dev, J, slot, sc, kb, mask,
@@ -646,6 +671,7 @@ def _run_scan(
                     lane_devices=cfg.devices,
                     guard=guard,
                 )
+                phase(obs.LAUNCH)
                 out = runner(
                     w0_dev, jnp.asarray(J), jnp.asarray(slot), jnp.asarray(sc),
                     jnp.asarray(kb), jnp.asarray(mask),
@@ -660,6 +686,7 @@ def _run_scan(
             if ckpt_on:
                 from .engine_ckpt import run_checkpointed_host
 
+                phase(obs.LAUNCH)
                 out = run_checkpointed_host(
                     _device_grad_fn(source), cfg.C, w0_dev,
                     stream.J, stream.slot, scale,
@@ -681,12 +708,15 @@ def _run_scan(
                     guard=guard,
                 )
                 J_dev, slot_dev = stream_arrays(stream)
+                phase(obs.LAUNCH)
                 out = runner(w0_dev, J_dev, slot_dev, jnp.asarray(scale))
         if guard is not None:
             w, evals, gcnt = out
         else:
             w, evals = out
+        phase(obs.WAIT)
         w = jax.block_until_ready(w)
+        phase(obs.READBACK)
         trace = TraceRecord(steps=np.arange(cfg.T), times=np.asarray(stream.t))
         trace.delays = stream.delays
         trace.mean_queue_lengths = (

@@ -37,6 +37,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from .. import obs
 from .engine_scan import (
     GuardConfig,
     _default_update,
@@ -110,12 +111,13 @@ def _save_state(ckpt_dir: str, step: int, tree, fingerprint: str, keep: int):
 
     from ..ckpt import checkpoint as ck
 
-    tree = jax.tree_util.tree_map(np.asarray, tree)
-    ck.save(
-        ckpt_dir, step, tree,
-        metadata={"fingerprint": fingerprint, "events_done": step},
-        keep=keep,
-    )
+    with obs.span(obs.CKPT_SAVE):
+        tree = jax.tree_util.tree_map(np.asarray, tree)
+        ck.save(
+            ckpt_dir, step, tree,
+            metadata={"fingerprint": fingerprint, "events_done": step},
+            keep=keep,
+        )
 
 
 class _AsyncSaver:

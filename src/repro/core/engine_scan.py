@@ -70,11 +70,12 @@ or after its arrival).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable, Protocol
 
 import numpy as np
 
+from .. import obs
 from .queue_sim import KIND_COMPLETE, EventBlocks, EventStream, FaultConfig
 from .theory import BoundConstants
 
@@ -105,6 +106,19 @@ class DeviceGradientSource(Protocol):
 
     def device_grad(self, client_id, params: Pytree, server_step) -> Pytree:
         ...
+
+
+def _scoped(scope: str, fn):
+    """``fn`` with the ops it traces under ``jax.named_scope(scope)``; a
+    scope opened inside it (the innermost) wins."""
+    import jax
+
+    @wraps(fn)
+    def f(*args, **kw):
+        with jax.named_scope(scope):
+            return fn(*args, **kw)
+
+    return f
 
 
 def step_scales(
@@ -425,7 +439,7 @@ def _make_apply_event(fedbuff_Z, enc, guard=None):
         snaps = snaps.at[s].set(enc(w))
         return (w, snaps, acc, gcnt)
 
-    return apply_event
+    return _scoped(obs.RING, apply_event)
 
 
 def _make_update_step(
@@ -458,7 +472,8 @@ def _make_update_step(
             w_disp = tree_map(lambda b: b[s], snaps)
         else:
             w_disp = unpack(snaps[s])
-        g = grad_fn(j, w_disp, k)
+        with jax.named_scope(obs.GRAD):
+            g = grad_fn(j, w_disp, k)
         if flat_mode:
             return apply_event(ucarry, pack(g), s, scale, k, stale)
         if fedbuff_Z > 0:
@@ -476,18 +491,20 @@ def _make_update_step(
             snaps = snaps.at[s].set(enc(pack(w)))
         return (w, snaps, acc, gcnt)
 
-    return update_step
+    return _scoped(obs.RING, update_step)
 
 
 def _make_batched_grads(grad_fn, pack, unpack):
     """Batched gradient call over one micro-block: (E,) client ids, (E, P)
     stored snapshot rows, (E,) server steps -> (E, P) packed gradients.
-    Shared by the host block step and the fused device window."""
+    Shared by the host block step and the fused device window; the
+    gradient calls are `obs.GRAD`, the row codec `obs.RING`."""
     import jax
 
-    return jax.vmap(
-        lambda j, wi, k: pack(grad_fn(j, unpack(wi), k))
-    )
+    grad = _scoped(obs.GRAD, grad_fn)
+    return _scoped(obs.RING, jax.vmap(
+        lambda j, wi, k: pack(grad(j, unpack(wi), k))
+    ))
 
 
 def _fedbuff_block_deltas(Gm, scm, k, m, acc, Z):
@@ -627,7 +644,7 @@ def _make_block_step(
         snaps, w = scatter_rows(snaps, w, W, s_all.reshape(E))
         return (w, snaps, acc, gcnt)
 
-    return block_step
+    return _scoped(obs.RING, block_step)
 
 
 def _init_update_carry(w0, rows, pack, unpack, flat_mode, fedbuff_Z, enc):
@@ -1087,7 +1104,8 @@ def _make_fused_advance(
             if serving_on:
                 # pre-event depth persists over dt: integrate it before
                 # the transition, on every (train or serve) event
-                sstats = sp.serve_time_step(sstats, sv, ev.dt)
+                with jax.named_scope(obs.SERVE):
+                    sstats = sp.serve_time_step(sstats, sv, ev.dt)
                 gcnt_pre = ucarry[3]
                 ucarry = update_step(ucarry, ev.j, ev.slot, scale, k, stale)
                 # unguarded runs never move gcnt: skip the per-step compare
@@ -1102,10 +1120,11 @@ def _make_fused_advance(
                 # here would marshal the ~25 serve-state buffers through
                 # the conditional every step, which costs more than the
                 # masked small ops themselves
-                sv, sstats = sp.serve_apply(
-                    serving, sv, sstats, u_ext, ev.t, k, ucarry[1],
-                    live=is_ext,
-                )
+                with jax.named_scope(obs.SERVE):
+                    sv, sstats = sp.serve_apply(
+                        serving, sv, sstats, u_ext, ev.t, k, ucarry[1],
+                        live=is_ext,
+                    )
             else:
                 ucarry = update_step(ucarry, ev.j, ev.slot, scale, k, stale)
             if need_stats:
@@ -1204,7 +1223,9 @@ def _make_fused_advance(
             snaps = ucarry[1]
             batched_grads = _make_batched_grads(grad_fn, pack, unpack)
             if axis is None:
-                G0 = batched_grads(jv, snaps[sv], kw)
+                with jax.named_scope(obs.RING):
+                    rows = snaps[sv]
+                G0 = batched_grads(jv, rows, kw)
             else:
                 # lane-sharded: this device differentiates E/D of the
                 # window's lanes; one all-gather recombines the batch
@@ -1212,8 +1233,11 @@ def _make_fused_advance(
                 d = jax.lax.axis_index(axis)
                 sl = lambda a: jax.lax.dynamic_slice_in_dim(a, d * El, El, 0)
                 jl, svl, kl = sl(jv), sl(sv), sl(kw)
-                Gl = batched_grads(jl, snaps[svl], kl)
-                G0 = jax.lax.all_gather(Gl, axis, tiled=True)
+                with jax.named_scope(obs.RING):
+                    rows = snaps[svl]
+                Gl = batched_grads(jl, rows, kl)
+                with jax.named_scope(obs.GRAD):
+                    G0 = jax.lax.all_gather(Gl, axis, tiled=True)
 
             apply_event = _make_apply_event(fedbuff_Z, enc, guard)
 
@@ -1223,13 +1247,15 @@ def _make_fused_advance(
                 else:
                     j, s, sc, conf, g0, k = xx
                     dl = None
-                row = cc[1][s]
-                g = jax.lax.cond(
-                    conf >= 0,
-                    lambda r: pack(grad_fn(j, unpack(r), k)),
-                    lambda r: g0,
-                    row,
-                )
+                with jax.named_scope(obs.RING):
+                    row = cc[1][s]
+                with jax.named_scope(obs.GRAD):
+                    g = jax.lax.cond(
+                        conf >= 0,
+                        lambda r: pack(grad_fn(j, unpack(r), k)),
+                        lambda r: g0,
+                        row,
+                    )
                 return apply_event(cc, g, s, sc, k, dl), ()
 
             xs_f = (jv, sv, scv, confv, G0, kw)
@@ -1258,8 +1284,8 @@ def _make_fused_advance(
             if nW:
                 resh = lambda a: a[:Wc].reshape(nW, E)
                 c, tsw = jax.lax.scan(
-                    window_body, c, (resh(ur), resh(ue), resh(Kc), resh(ks)),
-                    unroll=unroll,
+                    _scoped(obs.RACE, window_body), c,
+                    (resh(ur), resh(ue), resh(Kc), resh(ks)), unroll=unroll,
                 )
                 ts_parts.append(tsw.reshape(Wc))
             if Wc < Lc:
@@ -1269,7 +1295,9 @@ def _make_fused_advance(
                     xse = (ur[Wc:], ue[Wc:], Kc[Wc:], uph[Wc:], ks[Wc:])
                 else:
                     xse = (ur[Wc:], ue[Wc:], Kc[Wc:], ks[Wc:])
-                c, tse = jax.lax.scan(event_body, c, xse, unroll=unroll)
+                c, tse = jax.lax.scan(
+                    _scoped(obs.RACE, event_body), c, xse, unroll=unroll
+                )
                 ts_parts.append(tse)
             ts = ts_parts[0] if len(ts_parts) == 1 else jnp.concatenate(ts_parts)
             if serving_on:
@@ -1524,9 +1552,10 @@ def make_fused_runner(
             grad_fn, fedbuff_Z, update_fn, pack, unpack, flat_mode, enc, guard
         )
         rows = C + 1 if E > 1 else C
-        ucarry, to_tree = _init_update_carry(
-            w0, rows, pack, unpack, flat_mode, fedbuff_Z, enc
-        )
+        with jax.named_scope(obs.RING):
+            ucarry, to_tree = _init_update_carry(
+                w0, rows, pack, unpack, flat_mode, fedbuff_Z, enc
+            )
 
         mu = jnp.asarray(mu, jnp.float32)
         p0 = jnp.asarray(p0, jnp.float32)
@@ -1580,8 +1609,9 @@ def make_fused_runner(
             unroll=unroll, classes=classes, serving=serving,
             scenario=scen_on,
         )(mu, eta, fr)
-        sv0 = sp.serve_init(serving) if serving_on else None
-        sstats0 = sp.serve_stats_init() if serving_on else None
+        with jax.named_scope(obs.SERVE):
+            sv0 = sp.serve_init(serving) if serving_on else None
+            sstats0 = sp.serve_stats_init() if serving_on else None
 
         if sparse:
             # O(log m) class draw + uniform member — flat in n
@@ -1633,21 +1663,22 @@ def make_fused_runner(
                     counts=tuple(int(c) for c in classes.counts) if sparse
                     else None,
                 )
-            if not eval_on:
-                ev_val = jnp.float32(0.0)
-            elif eval_stride == 1:
-                ev_val = eval_fn(to_tree(ucarry[0]))
-            else:
-                # eval fires every eval_stride-th refresh chunk; the predicate
-                # is unbatched under vmap, so the cond stays a real branch and
-                # off-cadence chunks skip the eval work entirely
-                fire = ((k0 // L + 1) % eval_stride) == 0
-                ev_val = jax.lax.cond(
-                    fire,
-                    lambda u: jnp.asarray(eval_fn(to_tree(u)), jnp.float32),
-                    lambda u: jnp.float32(0.0),
-                    ucarry[0],
-                )
+            with jax.named_scope(obs.EVAL):
+                if not eval_on:
+                    ev_val = jnp.float32(0.0)
+                elif eval_stride == 1:
+                    ev_val = eval_fn(to_tree(ucarry[0]))
+                else:
+                    # eval fires every eval_stride-th refresh chunk; the
+                    # predicate is unbatched under vmap, so the cond stays a
+                    # real branch and off-cadence chunks skip the eval work
+                    fire = ((k0 // L + 1) % eval_stride) == 0
+                    ev_val = jax.lax.cond(
+                        fire,
+                        lambda u: jnp.asarray(eval_fn(to_tree(u)), jnp.float32),
+                        lambda u: jnp.float32(0.0),
+                        ucarry[0],
+                    )
             ys = (ts, ev_val, p) if collect_extras else (ev_val,)
             if serving_on:
                 return (ucarry, sstate, stats, slot_scale, p, sv, sstats), ys
@@ -1699,6 +1730,7 @@ def make_fused_runner(
             evals = evals[eval_stride - 1 :: eval_stride]
         else:
             evals = jnp.zeros((0,))
+        @partial(_scoped, obs.SERVE)
         def _serve_extras():
             return {
                 "serve_arrivals": sstats.arrivals,
@@ -1719,6 +1751,8 @@ def make_fused_runner(
                 "serve_t_final": sstate.t,
             }
 
+        with jax.named_scope(obs.RING):
+            w_final = to_tree(ucarry[0])
         if not collect_extras:
             extras = {"p_final": p}
             if guard is not None:
@@ -1726,7 +1760,7 @@ def make_fused_runner(
                 extras["stale_drops"] = ucarry[3][1]
             if serving_on:
                 extras.update(_serve_extras())
-            return to_tree(ucarry[0]), evals, extras
+            return w_final, evals, extras
         extras = {
             "t": ts,
             "p_final": p,
@@ -1748,8 +1782,11 @@ def make_fused_runner(
             extras["class_counts"] = jnp.asarray(classes.counts, jnp.int32)
         if serving_on:
             extras.update(_serve_extras())
-        return to_tree(ucarry[0]), evals, extras
+        return w_final, evals, extras
 
+    # what no inner scope claims -- the draws, the race's state and
+    # statistics, dispatch sampling -- is the race's
+    run = _scoped(obs.RACE, run)
     if not wrap_lanes:
         return run
 
@@ -1923,6 +1960,7 @@ def jit_runner(
     if key in cache:
         jitted = cache[key]
         return jitted if block_size > 1 else partial(jitted, eval_every=eval_every)
+    obs.counters["runner_builds"] += 1
     if block_size > 1:
         # the factory owns the scenario vmap and (if any) the lane shard_map
         run = _make_host_block_runner(
@@ -2009,6 +2047,7 @@ def jit_fused_runner(
         kw_key,
     )
     if key not in cache:
+        obs.counters["runner_builds"] += 1
         if lane_devices > 1 and vmap_scenarios:
             from jax.sharding import Mesh, PartitionSpec as P
 

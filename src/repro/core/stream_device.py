@@ -49,6 +49,7 @@ from typing import Any, NamedTuple
 
 import numpy as np
 
+from .. import obs
 from .queue_sim import (
     KIND_COMPLETE,
     KIND_CRASH,
@@ -1986,7 +1987,6 @@ def ctrl_refresh(
     import jax.numpy as jnp
 
     vg = make_bound_value_and_grad(k, counts=counts)
-    mu_hat = estimate_mu(comp, busy_t)
     if counts is None:
         w = jnp.ones_like(p)
         n = p.shape[0]
@@ -2006,5 +2006,7 @@ def ctrl_refresh(
         z = jnp.maximum(z, w * floor)
         return (z / jnp.sum(z)) / w, None
 
-    p, _ = jax.lax.scan(one, p, None, length=iters)
+    with jax.named_scope(obs.CTRL):
+        mu_hat = estimate_mu(comp, busy_t)
+        p, _ = jax.lax.scan(one, p, None, length=iters)
     return p
