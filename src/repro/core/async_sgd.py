@@ -838,7 +838,7 @@ def _python_fault_loop(
     timeouts discard the in-flight work and re-dispatch the freed slot with
     the *current* server weights; availability flips touch no task.  The
     guard ordering (staleness before divergence, rejects counted once)
-    matches `engine_scan._make_flat_guard` exactly, with the staleness clock
+    matches `engine_scan._make_guard` exactly, with the staleness clock
     being merged-event steps — the same counter the device stream scans
     over.
     """

@@ -25,9 +25,10 @@ draw order than `make_fused_runner`'s single upfront ``(T,)`` draw, so the
 checkpointed fused engine is its own deterministic trajectory; its law is
 identical.
 
-A config fingerprint is stored in every checkpoint's metadata and validated
-on ``resume=True`` — resuming under a different engine configuration is an
-error, not a silent divergence.
+A config fingerprint and the snapshot ring's layout are stored in every
+checkpoint's metadata and validated on ``resume=True`` — resuming under a
+different engine configuration, or a checkpoint whose ring is laid out
+otherwise, is an error, not a silent divergence.
 """
 from __future__ import annotations
 
@@ -41,12 +42,13 @@ from .. import obs
 from .engine_scan import (
     GuardConfig,
     _default_update,
+    _flat_ring,
     _init_update_carry,
+    _leaf_ring,
     _make_block_step,
     _make_fused_advance,
     _make_update_step,
     _runner_cache,
-    _snapshot_codec,
 )
 from .queue_sim import FaultConfig
 from .theory import BoundConstants
@@ -87,8 +89,17 @@ def _key_fingerprint(key) -> list:
     return [int(x) for x in np.ravel(kd)]
 
 
-def _resume_state(ckpt_dir: str, like, fingerprint: str):
-    """Latest checkpoint tree (validated against ``fingerprint``) or None."""
+#: how a checkpoint's carry holds the snapshot ring, by its ``ring`` metadata
+#: (checkpoints written before the per-leaf ring carry none: "flat")
+_RING_LAYOUTS = {
+    "flat": "as one flat (C, P) array of packed rows",
+    "leaf": "as one (C, *leaf.shape) buffer per parameter leaf",
+}
+
+
+def _resume_state(ckpt_dir: str, like, fingerprint: str, ring: str = "flat"):
+    """Latest checkpoint tree (validated against ``fingerprint`` and the
+    ring layout ``ring``) or None."""
     from ..ckpt import checkpoint as ck
 
     step = ck.latest_step(ckpt_dir)
@@ -97,6 +108,14 @@ def _resume_state(ckpt_dir: str, like, fingerprint: str):
             f"resume=True but no checkpoint found under {ckpt_dir!r}"
         )
     meta = ck.load_metadata(ckpt_dir, step)
+    got = meta.get("ring", "flat")
+    if got != ring:
+        raise ValueError(
+            f"checkpoint/config mismatch: the checkpoint under {ckpt_dir!r} "
+            f"holds the snapshot ring {_RING_LAYOUTS.get(got, got)}, but this "
+            f"engine keeps it {_RING_LAYOUTS[ring]}; a checkpoint cannot be "
+            "resumed across ring layouts (start the run afresh)"
+        )
     if meta.get("fingerprint") != fingerprint:
         raise ValueError(
             "checkpoint/config mismatch: the run under "
@@ -106,7 +125,8 @@ def _resume_state(ckpt_dir: str, like, fingerprint: str):
     return ck.restore(ckpt_dir, step, like), step
 
 
-def _save_state(ckpt_dir: str, step: int, tree, fingerprint: str, keep: int):
+def _save_state(ckpt_dir: str, step: int, tree, fingerprint: str, keep: int,
+                ring: str = "flat"):
     import jax
 
     from ..ckpt import checkpoint as ck
@@ -115,7 +135,8 @@ def _save_state(ckpt_dir: str, step: int, tree, fingerprint: str, keep: int):
         tree = jax.tree_util.tree_map(np.asarray, tree)
         ck.save(
             ckpt_dir, step, tree,
-            metadata={"fingerprint": fingerprint, "events_done": step},
+            metadata={"fingerprint": fingerprint, "events_done": step,
+                      "ring": ring},
             keep=keep,
         )
 
@@ -135,11 +156,13 @@ class _AsyncSaver:
     always on disk when the run completes.
     """
 
-    def __init__(self, ckpt_dir: str, fingerprint: str, keep: int):
+    def __init__(self, ckpt_dir: str, fingerprint: str, keep: int,
+                 ring: str = "flat"):
         import queue
         import threading
 
         self._dir, self._fp, self._keep = ckpt_dir, fingerprint, keep
+        self._ring = ring
         self._q: Any = queue.Queue(maxsize=2)
         self._err: BaseException | None = None
         self._worker = threading.Thread(target=self._loop, daemon=True)
@@ -153,7 +176,8 @@ class _AsyncSaver:
             step, tree = item
             try:
                 if self._err is None:
-                    _save_state(self._dir, step, tree, self._fp, self._keep)
+                    _save_state(self._dir, step, tree, self._fp, self._keep,
+                                self._ring)
             except BaseException as exc:  # surfaced at put()/close()
                 self._err = exc
 
@@ -300,20 +324,14 @@ def run_checkpointed(
     bound = bound if bound is not None else BoundConstants(C=C, T=T)
 
     update_fn, default_update = _default_update(None)
-    pack, unpack, enc = _snapshot_codec(w0, snapshot_dtype)
-    flat_mode = default_update and unpack is not None
-    if E > 1 and not flat_mode:
-        raise ValueError(
-            "block_size > 1 requires all-float parameters "
-            "(flat-packed snapshot storage)"
-        )
-    update_step = _make_update_step(
-        grad_fn, 0, update_fn, pack, unpack, flat_mode, enc, guard
-    )
-    rows = C + 1 if E > 1 else C
-    ucarry0, to_tree = _init_update_carry(
-        w0, rows, pack, unpack, flat_mode, 0, enc
-    )
+    if E > 1:
+        ring, rows, layout = _flat_ring(w0, snapshot_dtype), C + 1, "flat"
+    else:
+        ring = _leaf_ring(w0, snapshot_dtype, drop=faulty or serving_on)
+        rows, layout = C, "leaf"
+    master = default_update and ring.master is not None
+    update_step = _make_update_step(grad_fn, 0, update_fn, ring, master, guard)
+    ucarry0, to_tree = _init_update_carry(w0, rows, ring, master, 0)
 
     mu = jnp.asarray(mu, jnp.float32)
     p0 = jnp.asarray(p0, jnp.float32)
@@ -356,7 +374,7 @@ def run_checkpointed(
         jchunk = cache[memo_key]
     else:
         make_adv = _make_fused_advance(
-            grad_fn, n, C, E, update_step, pack, unpack, enc, 0, guard,
+            grad_fn, n, C, E, update_step, ring, 0, guard,
             importance=importance, faulty=faulty, guard_stale=guard_stale,
             need_stats=need_stats, axis=None, lane_devices=1, unroll=unroll,
             serving=serving,
@@ -405,7 +423,7 @@ def run_checkpointed(
         n=n, C=C, T=T, L=L, ckpt_every=ckpt_every, weighting=weighting,
         eval_every=eval_every if eval_on else 0, adaptive=adaptive,
         refresh_every=refresh_every, init=init, block_size=E,
-        snapshot_dtype=str(snapshot_dtype),
+        snapshot_dtype=str(snapshot_dtype), ring=layout,
         fault=None if fault is None else fault.cache_key(),
         guard=None if guard is None else guard.cache_key(),
         serving=None if not serving_on else serving.cache_key(),
@@ -419,12 +437,12 @@ def run_checkpointed(
             "cursor": np.int64(0)}
     carry, evals, cursor0 = carry0, _EvalBuffer(n_evals), 0
     if resume:
-        state, _ = _resume_state(ckpt_dir, like, fingerprint)
+        state, _ = _resume_state(ckpt_dir, like, fingerprint, layout)
         carry = jax.tree_util.tree_map(jnp.asarray, state["carry"])
         evals = _EvalBuffer(n_evals, restored=state["evals"])
         cursor0 = int(state["cursor"])
 
-    saver = _AsyncSaver(ckpt_dir, fingerprint, keep)
+    saver = _AsyncSaver(ckpt_dir, fingerprint, keep, layout)
     try:
         for c in range(cursor0 // L, n_chunks):
             do_eval = eval_on and ((c + 1) % eval_stride == 0)
@@ -533,14 +551,12 @@ def run_checkpointed_host(
     eval_stride = max(eval_every // L, 1) if eval_on else 0
 
     update_fn, default_update = _default_update(update_fn)
-    pack, unpack, enc = _snapshot_codec(w0, snapshot_dtype)
-    flat_mode = default_update and unpack is not None
+    ring = _leaf_ring(w0, snapshot_dtype, drop=True)
+    master = default_update and ring.master is not None
     update_step = _make_update_step(
-        grad_fn, fedbuff_Z, update_fn, pack, unpack, flat_mode, enc, guard
+        grad_fn, fedbuff_Z, update_fn, ring, master, guard
     )
-    carry0, to_tree = _init_update_carry(
-        w0, C, pack, unpack, flat_mode, fedbuff_Z, enc
-    )
+    carry0, to_tree = _init_update_carry(w0, C, ring, master, fedbuff_Z)
 
     def chunk(carry, Jc, sc_, scc, k0, do_eval):
         def body(c, xs):
@@ -560,7 +576,7 @@ def run_checkpointed_host(
     fingerprint = _fingerprint("host", dict(
         C=C, T=T, L=L, ckpt_every=ckpt_every, fedbuff_Z=fedbuff_Z,
         eval_every=eval_every if eval_on else 0,
-        snapshot_dtype=str(snapshot_dtype),
+        snapshot_dtype=str(snapshot_dtype), ring="leaf",
         guard=None if guard is None else guard.cache_key(),
         stream=_array_digest(J, slot_h, scale_h),
     ))
@@ -569,12 +585,12 @@ def run_checkpointed_host(
             "cursor": np.int64(0)}
     carry, evals, cursor0 = carry0, _EvalBuffer(n_evals), 0
     if resume:
-        state, _ = _resume_state(ckpt_dir, like, fingerprint)
+        state, _ = _resume_state(ckpt_dir, like, fingerprint, "leaf")
         carry = jax.tree_util.tree_map(jnp.asarray, state["carry"])
         evals = _EvalBuffer(n_evals, restored=state["evals"])
         cursor0 = int(state["cursor"])
 
-    saver = _AsyncSaver(ckpt_dir, fingerprint, keep)
+    saver = _AsyncSaver(ckpt_dir, fingerprint, keep, "leaf")
     try:
         for c in range(cursor0 // L, n_chunks):
             lo, hi = c * L, (c + 1) * L
@@ -666,18 +682,9 @@ def run_checkpointed_host_blocked(
     k_h = np.asarray(k, np.int32)
     mask_h = np.asarray(mask, bool)
 
-    pack, unpack, enc = _snapshot_codec(w0, snapshot_dtype, pad_to=pad_to)
-    if unpack is None:
-        raise ValueError(
-            "block_size > 1 requires all-float parameters "
-            "(flat-packed snapshot storage)"
-        )
-    block_step = _make_block_step(
-        grad_fn, fedbuff_Z, pack, unpack, kernel, None, guard
-    )
-    carry0, to_tree = _init_update_carry(
-        w0, C + 1, pack, unpack, True, fedbuff_Z, enc
-    )
+    ring = _flat_ring(w0, snapshot_dtype, pad_to=pad_to)
+    block_step = _make_block_step(grad_fn, fedbuff_Z, ring, kernel, None, guard)
+    carry0, to_tree = _init_update_carry(w0, C + 1, ring, True, fedbuff_Z)
 
     def chunk(carry, Jc, sc_, scc, kc, mc, do_eval):
         def body(c, xs):

@@ -6,11 +6,13 @@ structure removes the need for that: the event stream (J_k, K_{k+1}, t_k) of
 the closed Jackson network is independent of the gradient values, so
 Algorithm 1 runs on device as a single XLA program:
 
-  * the C in-flight dispatch snapshots live in a stacked ring buffer
-    (a (C, ...) leading axis on every parameter leaf; flat-packed into ONE
-    (C, P) array when dtypes allow, optionally stored in a narrower dtype —
+  * the C in-flight dispatch snapshots live in a stacked ring buffer: one
+    (C, *leaf.shape) buffer per parameter leaf, the ring index the major
+    axis, so a slot's read and write touch only its own bytes (the blocked
+    engine packs its ring into ONE (C+1, P) array of flat rows for its
+    kernels); optionally stored in a narrower dtype —
     ``snapshot_dtype="bfloat16"`` halves the ring buffer's footprint and
-    bandwidth at an O(2^-8) snapshot quantization);
+    bandwidth at an O(2^-8) snapshot quantization;
   * `update_step` — the algorithm half — gathers the completing task's
     snapshot from its slot, computes the client gradient with a traceable
     `grad_fn(j, w, k)`, applies the importance-weighted update, and scatters
@@ -71,7 +73,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial, wraps
-from typing import Any, Callable, Protocol
+from typing import Any, Callable, NamedTuple, Protocol
 
 import numpy as np
 
@@ -268,20 +270,18 @@ def blocked_inputs_batch(
 
 
 # ------------------------------------------------------------------ #
-# shared pieces: snapshot codec + the algorithm step
+# shared pieces: the snapshot ring + the algorithm step
 # ------------------------------------------------------------------ #
 def _snapshot_codec(w0, snapshot_dtype=None, pad_to: int = 1):
-    """Flat-packed snapshot storage for any all-float parameter pytree.
+    """Flat-packed rows for the blocked engine's snapshot ring.
 
-    The ring buffer then is ONE (C, P) array — a single gather/scatter
-    per step instead of two per leaf, which matters for small models
-    where per-op overhead inside the scan dominates.  Uniform-dtype trees
+    The blocked engine's ring is ONE (C+1, P) array: its batched gather,
+    prefix-sum and scatter kernels work on flat rows.  Uniform-dtype trees
     pack losslessly; mixed *float* trees (e.g. bf16 matmul weights with
     fp32 norms — the real-model presets) pack per-leaf into the common
     promoted dtype (``jnp.result_type`` over the leaves, so bf16+fp32
     packs to an fp32 master vector) and ``unpack`` casts each leaf back
-    to its original dtype.  Only trees with non-float leaves fall back to
-    per-leaf (C, ...) buffers.
+    to its original dtype.  Trees with non-float leaves cannot be packed.
 
     Returns ``(pack, unpack, enc)``: ``pack`` flattens a pytree to the
     padded compute-dtype vector, ``unpack`` restores the pytree from a
@@ -290,6 +290,7 @@ def _snapshot_codec(w0, snapshot_dtype=None, pad_to: int = 1):
     ``snapshot_dtype`` codec (e.g. ``"bfloat16"`` ring storage for fp32
     params).  ``pad_to`` rounds the packed length up (once, at init) so the
     fused block kernel's column tiling never re-pads inside the scan.
+    Non-float trees return ``(None, None, None)``.
     """
     import jax
     import jax.numpy as jnp
@@ -303,7 +304,7 @@ def _snapshot_codec(w0, snapshot_dtype=None, pad_to: int = 1):
                 "snapshot_dtype requires all-float parameters "
                 "(flat-packed snapshot storage)"
             )
-        return None, None, None  # non-float leaves: per-leaf buffers
+        return None, None, None
     compute_dtype = jnp.result_type(*dtypes) if len(dtypes) > 1 else dtypes.pop()
     store_dtype = (
         jnp.dtype(snapshot_dtype) if snapshot_dtype is not None else compute_dtype
@@ -337,6 +338,119 @@ def _snapshot_codec(w0, snapshot_dtype=None, pad_to: int = 1):
     return pack, unpack, enc
 
 
+class _Ring(NamedTuple):
+    """How the snapshot ring is stored and addressed.
+
+    The update step reads and writes one slot per event through these,
+    whatever the layout; ``master`` is None for a tree that has no master
+    iterate (non-float leaves), which then trains in its own dtypes.
+    """
+
+    fill: Callable            # (w0, rows) -> ring, every row holding w0
+    read: Callable            # (snaps, s) -> params pytree in the leaf dtypes
+    write: Callable           # (snaps, s, w) -> snaps, slot s holding w
+    master: Callable | None   # params pytree -> master layout, compute dtype
+    to_tree: Callable         # master layout (or a stored row) -> params
+
+
+def _leaf_ring(w0, snapshot_dtype=None, drop: bool = False) -> _Ring:
+    """The per-event engine's ring: one ``(C, *leaf.shape)`` buffer per
+    parameter leaf, with the ring index as the major axis.
+
+    A slot's read is a ``dynamic_index_in_dim`` and its write an in-place
+    dynamic-update-slice on each leaf, so both touch only that slot's
+    bytes.  (One flat (C, P) array puts C on the chip's tiled row axis:
+    every single-row access then works on tiles shared by 8 rows, and the
+    row must be re-laid out to slice the leaves out of it.)  On an
+    all-float tree the master iterate is kept per leaf in the promoted
+    compute dtype (``jnp.result_type`` over the leaves: bf16 matmul weights
+    with fp32 norms keep an fp32 master), the ring stores that dtype or
+    ``snapshot_dtype``, and a read casts back to each leaf's dtype.  A tree
+    with non-float leaves stores each leaf in its own dtype.
+
+    ``drop`` is for event sources that emit slot C (fault, flip, scenario
+    stage and serve events): their writes are dropped, never clamped onto
+    row C-1.  Otherwise every slot is in bounds and the write is unmasked.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    leaves, treedef = jax.tree_util.tree_flatten(w0)
+    dtypes = [jnp.asarray(l).dtype for l in leaves]
+    shapes = [jnp.shape(l) for l in leaves]
+    # a row of fewer than two axes gets leading 1s, so that C never lands
+    # on the chip's tiled (8, 128) axes
+    row_shapes = [(1,) * (2 - len(sh)) + sh for sh in shapes]
+    if all(jnp.issubdtype(d, jnp.inexact) for d in dtypes):
+        compute = jnp.result_type(*dtypes)
+        store = compute if snapshot_dtype is None else jnp.dtype(snapshot_dtype)
+        stored = [store] * len(dtypes)
+    elif snapshot_dtype is not None:
+        raise ValueError("snapshot_dtype requires all-float parameters")
+    else:
+        compute, stored = None, dtypes
+
+    def fill(w0, rows):
+        return treedef.unflatten([
+            jnp.broadcast_to(jnp.asarray(x, d).reshape(r)[None], (rows,) + r)
+            for x, d, r in zip(jax.tree_util.tree_leaves(w0), stored, row_shapes)
+        ])
+
+    def read(snaps, s):
+        return treedef.unflatten([
+            jax.lax.dynamic_index_in_dim(b, s, 0, keepdims=False)
+            .reshape(sh).astype(d)
+            for b, sh, d in zip(jax.tree_util.tree_leaves(snaps), shapes, dtypes)
+        ])
+
+    def write(snaps, s, w):
+        def put(b, x):
+            x = x.astype(b.dtype).reshape(b.shape[1:])
+            if drop:
+                return b.at[s].set(x, mode="drop")
+            return jax.lax.dynamic_update_index_in_dim(b, x, s, 0)
+
+        return jax.tree_util.tree_map(put, snaps, w)
+
+    def master(tree):
+        return jax.tree_util.tree_map(lambda x: jnp.asarray(x, compute), tree)
+
+    def to_tree(w):
+        return treedef.unflatten([
+            x.astype(d) for x, d in zip(jax.tree_util.tree_leaves(w), dtypes)
+        ])
+
+    return _Ring(fill, read, write, None if compute is None else master, to_tree)
+
+
+def _flat_ring(w0, snapshot_dtype=None, pad_to: int = 1) -> _Ring:
+    """The blocked engine's ring: ONE (C+1, P) array of `_snapshot_codec`
+    rows, the layout its batched gather and Pallas prefix/scatter kernels
+    work on.  Its slots are always in bounds: padded lanes and slot-C
+    events target the trash row C.  The master iterate is the packed
+    vector, and ``to_tree`` also decodes a stored row."""
+    import jax.numpy as jnp
+
+    pack, unpack, enc = _snapshot_codec(w0, snapshot_dtype, pad_to=pad_to)
+    if unpack is None:
+        raise ValueError(
+            "block_size > 1 requires all-float parameters "
+            "(flat-packed snapshot storage)"
+        )
+
+    def fill(w0, rows):
+        stored0 = enc(pack(w0))
+        return jnp.broadcast_to(stored0[None], (rows, stored0.shape[0]))
+
+    return _Ring(
+        fill=fill,
+        read=lambda snaps, s: unpack(snaps[s]),
+        write=lambda snaps, s, w: snaps.at[s].set(enc(w)),
+        master=pack,
+        to_tree=unpack,
+    )
+
+
 @dataclass(frozen=True)
 class GuardConfig:
     """Divergence guard on the server update (graceful degradation).
@@ -354,8 +468,8 @@ class GuardConfig:
          event stream's queue dynamics are untouched.
 
     Guarded runners return the (2,) int32 counter ``[guard_rejects,
-    stale_drops]`` alongside the final iterate.  Requires the flat-packed
-    snapshot codec (uniform parameter dtype, default linear update).
+    stale_drops]`` alongside the final iterate.  Requires all-float
+    parameters and the default linear update.
     """
 
     max_grad_norm: float = 0.0
@@ -369,10 +483,11 @@ class GuardConfig:
         return (float(self.max_grad_norm), int(self.stale_cutoff))
 
 
-def _make_flat_guard(guard: GuardConfig):
-    """``check(g, scale, gcnt, stale) -> (bad, scale, gcnt)`` on one packed
-    gradient — the single place the guard semantics (ordering, counting)
-    live; every engine path composes it around its update axpy.
+def _make_guard(guard: GuardConfig):
+    """``check(g, scale, gcnt, stale) -> (bad, scale, gcnt)`` on one
+    gradient in the master layout (its squared norm sums over the leaves)
+    — the single place the guard semantics (ordering, counting) live;
+    every engine path composes it around its update axpy.
 
     The divergence verdict ``bad`` is returned instead of a pre-zeroed
     gradient so the caller can compute the candidate update concurrently
@@ -381,6 +496,7 @@ def _make_flat_guard(guard: GuardConfig):
     serialize the axpy behind the full reduction over ``g``, an extra
     sweep of stall per event.
     """
+    import jax
     import jax.numpy as jnp
 
     max_sq = float(guard.max_grad_norm) ** 2
@@ -393,7 +509,10 @@ def _make_flat_guard(guard: GuardConfig):
             gcnt = gcnt.at[1].add(st.astype(jnp.int32))
             scale = jnp.where(st, 0.0, scale)
             live = live & ~st
-        sq = jnp.sum(jnp.square(g.astype(jnp.float32)))
+        sq = sum(
+            jnp.sum(jnp.square(x.astype(jnp.float32)))
+            for x in jax.tree_util.tree_leaves(g)
+        )
         bad = ~jnp.isfinite(sq)
         if max_sq > 0.0:
             bad = bad | (sq > max_sq)
@@ -403,20 +522,22 @@ def _make_flat_guard(guard: GuardConfig):
     return check
 
 
-def _make_apply_event(fedbuff_Z, enc, guard=None):
-    """Flat-mode server update for one event, given its (packed) gradient.
+def _make_apply_event(fedbuff_Z, ring: _Ring, guard=None):
+    """Server update for one event, given its gradient in the master layout.
 
     ``apply_event((w, snaps, acc, gcnt), g, s, scale, k[, stale])`` is
-    Algorithm 1 lines 10-11 on the packed vector — one axpy, one scatter
-    (plus the masked FedBuff buffer flush every Z-th step).  Shared by the
-    per-event `update_step` and the device-blocked fixup pass so the update
-    semantics exist exactly once.  With ``guard`` the gradient passes the
-    divergence/staleness check first and the carry's (2,) reject counter
-    accumulates.
+    Algorithm 1 lines 10-11 — the axpy on the master iterate, one write of
+    slot s through ``ring`` (plus the masked FedBuff buffer flush every
+    Z-th step).  Shared by the per-event `update_step` and the
+    device-blocked fixup pass so the update semantics exist exactly once.
+    With ``guard`` the gradient passes the divergence/staleness check first
+    and the carry's (2,) reject counter accumulates.
     """
+    import jax
     import jax.numpy as jnp
 
-    check = _make_flat_guard(guard) if guard is not None else None
+    tree_map = jax.tree_util.tree_map
+    check = _make_guard(guard) if guard is not None else None
 
     def apply_event(ucarry, g, s, scale, k, stale=None):
         w, snaps, acc, gcnt = ucarry
@@ -425,26 +546,27 @@ def _make_apply_event(fedbuff_Z, enc, guard=None):
             bad, scale, gcnt = check(g, scale, gcnt, stale)
         if fedbuff_Z > 0:
             if bad is not None:  # the accumulator consumes g beyond the axpy
-                g = jnp.where(bad, jnp.zeros_like(g), g)
-            acc = acc + g
+                g = tree_map(lambda y: jnp.where(bad, jnp.zeros_like(y), y), g)
+            acc = tree_map(lambda a, y: a + y, acc, g)
             fire = ((k + 1) % fedbuff_Z) == 0
             eff = jnp.where(fire, scale / fedbuff_Z, 0.0)
-            w = (w - eff * acc).astype(w.dtype)
-            acc = acc * (~fire).astype(acc.dtype)
+            w = tree_map(lambda x, a: (x - eff * a).astype(x.dtype), w, acc)
+            acc = tree_map(lambda a: a * (~fire).astype(a.dtype), acc)
         else:
-            w_new = (w - scale * g).astype(w.dtype)
+            w_new = tree_map(lambda x, y: (x - scale * y).astype(x.dtype), w, g)
             # candidate axpy and norm reduction run concurrently; rejection
             # is a select on the result, not a stall before it
-            w = jnp.where(bad, w, w_new) if bad is not None else w_new
-        snaps = snaps.at[s].set(enc(w))
+            w = w_new if bad is None else tree_map(
+                lambda a, b: jnp.where(bad, a, b), w, w_new
+            )
+        snaps = ring.write(snaps, s, w)
         return (w, snaps, acc, gcnt)
 
     return _scoped(obs.RING, apply_event)
 
 
-def _make_update_step(
-    grad_fn, fedbuff_Z, update_fn, pack, unpack, flat_mode, enc, guard=None
-):
+def _make_update_step(grad_fn, fedbuff_Z, update_fn, ring: _Ring, master,
+                      guard=None):
     """The algorithm half of a CS step, independent of the event source.
 
     ``update_step(ucarry, j, s, scale, k[, stale]) -> ucarry`` consumes one
@@ -452,30 +574,30 @@ def _make_update_step(
     exactly as Algorithm 1 lines 9-11 — both the host-replay scan body and
     the fused device-stream body compose it with their event producer.
     ``stale`` (server steps the task spent in flight) feeds the optional
-    staleness cutoff of ``guard``.
+    staleness cutoff of ``guard``.  With ``master`` (the default update on
+    an all-float tree) ``w`` is the master iterate and the update is
+    `_make_apply_event`'s; otherwise ``w`` is the parameter pytree and
+    ``update_fn`` steps it.
     """
     import jax
     import jax.numpy as jnp
 
-    if guard is not None and not flat_mode:
+    if guard is not None and not master:
         raise ValueError(
-            "the divergence guard requires the flat-packed snapshot codec "
-            "(uniform-dtype parameters, default linear update)"
+            "the divergence guard requires all-float parameters and the "
+            "default linear update"
         )
     tree_map = jax.tree_util.tree_map
-    apply_event = _make_apply_event(fedbuff_Z, enc, guard) if flat_mode else None
+    apply_event = _make_apply_event(fedbuff_Z, ring, guard) if master else None
 
     def update_step(ucarry, j, s, scale, k, stale=None):
-        w, snaps, acc, gcnt = ucarry  # w (and acc) are flat in flat_mode
+        w, snaps, acc, gcnt = ucarry
         # gather the completing task's dispatch-time snapshot (Alg. 1 line 9)
-        if unpack is None:
-            w_disp = tree_map(lambda b: b[s], snaps)
-        else:
-            w_disp = unpack(snaps[s])
+        w_disp = ring.read(snaps, s)
         with jax.named_scope(obs.GRAD):
             g = grad_fn(j, w_disp, k)
-        if flat_mode:
-            return apply_event(ucarry, pack(g), s, scale, k, stale)
+        if master:
+            return apply_event(ucarry, ring.master(g), s, scale, k, stale)
         if fedbuff_Z > 0:
             acc = tree_map(lambda a, y: a + y, acc, g)
             fire = ((k + 1) % fedbuff_Z) == 0
@@ -485,25 +607,21 @@ def _make_update_step(
         else:
             w = update_fn(w, g, scale)
         # the freed slot hosts the new dispatch with the updated params
-        if unpack is None:
-            snaps = tree_map(lambda b, x: b.at[s].set(x), snaps, w)
-        else:
-            snaps = snaps.at[s].set(enc(pack(w)))
-        return (w, snaps, acc, gcnt)
+        return (w, ring.write(snaps, s, w), acc, gcnt)
 
     return _scoped(obs.RING, update_step)
 
 
-def _make_batched_grads(grad_fn, pack, unpack):
-    """Batched gradient call over one micro-block: (E,) client ids, (E, P)
-    stored snapshot rows, (E,) server steps -> (E, P) packed gradients.
-    Shared by the host block step and the fused device window; the
-    gradient calls are `obs.GRAD`, the row codec `obs.RING`."""
+def _make_batched_grads(grad_fn, ring: _Ring):
+    """Batched gradient call over one micro-block of the flat ring: (E,)
+    client ids, (E, P) stored rows, (E,) server steps -> (E, P) packed
+    gradients.  Shared by the host block step and the fused device window;
+    the gradient calls are `obs.GRAD`, the row codec `obs.RING`."""
     import jax
 
     grad = _scoped(obs.GRAD, grad_fn)
     return _scoped(obs.RING, jax.vmap(
-        lambda j, wi, k: pack(grad(j, unpack(wi), k))
+        lambda j, wi, k: ring.master(grad(j, ring.to_tree(wi), k))
     ))
 
 
@@ -540,9 +658,9 @@ def _fedbuff_block_deltas(Gm, scm, k, m, acc, Z):
 
 
 def _make_block_step(
-    grad_fn, fedbuff_Z, pack, unpack, kernel, lane_axis=None, guard=None,
+    grad_fn, fedbuff_Z, ring: _Ring, kernel, lane_axis=None, guard=None,
 ):
-    """One event micro-block of the blocked engine (flat-packed mode only).
+    """One event micro-block of the blocked engine over the flat ring.
 
     ``block_step(ucarry, j, s, scale, k, mask) -> ucarry`` consumes up to E
     conflict-free events: one batched snapshot gather, one vmapped gradient
@@ -585,7 +703,7 @@ def _make_block_step(
     else:
         raise ValueError(kernel)
 
-    grads = _make_batched_grads(grad_fn, pack, unpack)
+    grads = _make_batched_grads(grad_fn, ring)
     max_sq = float(guard.max_grad_norm) ** 2 if guard is not None else 0.0
 
     def guard_rows(G, scm, gcnt):
@@ -647,32 +765,27 @@ def _make_block_step(
     return _scoped(obs.RING, block_step)
 
 
-def _init_update_carry(w0, rows, pack, unpack, flat_mode, fedbuff_Z, enc):
+def _init_update_carry(w0, rows, ring: _Ring, master, fedbuff_Z):
     """(w, snaps, acc, gcnt) initial carry + the carry->pytree decoder.
 
     ``rows`` is the snapshot ring height — C for the per-event engine, C+1
     for the blocked engine (the extra trash row absorbs padded scatters).
-    ``gcnt`` is the (2,) int32 ``[guard_rejects, stale_drops]`` counter —
-    carried unconditionally (two scalars) so the carry structure does not
-    depend on whether a guard is active.
+    With ``master`` the carried ``w`` is the master iterate in the ring's
+    compute dtype, else the parameter pytree itself.  ``gcnt`` is the (2,)
+    int32 ``[guard_rejects, stale_drops]`` counter — carried
+    unconditionally (two scalars) so the carry structure does not depend on
+    whether a guard is active.
     """
     import jax
     import jax.numpy as jnp
 
-    tree_map = jax.tree_util.tree_map
-    if unpack is None:
-        snaps0 = tree_map(
-            lambda x: jnp.broadcast_to(x[None], (rows,) + jnp.shape(x)), w0
-        )
-        w_init = w0
-    else:
-        flat0 = pack(w0)
-        stored0 = enc(flat0)
-        snaps0 = jnp.broadcast_to(stored0[None], (rows, stored0.shape[0]))
-        w_init = flat0 if flat_mode else w0
-    acc0 = tree_map(jnp.zeros_like, w_init) if fedbuff_Z > 0 else ()
-    to_tree = (lambda w: unpack(w)) if flat_mode else (lambda w: w)
-    return (w_init, snaps0, acc0, jnp.zeros((2,), jnp.int32)), to_tree
+    w_init = ring.master(w0) if master else w0
+    acc0 = (
+        jax.tree_util.tree_map(jnp.zeros_like, w_init) if fedbuff_Z > 0 else ()
+    )
+    to_tree = ring.to_tree if master else (lambda w: w)
+    gcnt0 = jnp.zeros((2,), jnp.int32)
+    return (w_init, ring.fill(w0, rows), acc0, gcnt0), to_tree
 
 
 def _default_update(update_fn):
@@ -729,10 +842,11 @@ def _make_host_runner(
     eval_every_default = eval_every
 
     def run(w0, J, slot, scale, eval_every=eval_every_default):
-        pack, unpack, enc = _snapshot_codec(w0, snapshot_dtype)
-        flat_mode = default_update and unpack is not None
+        # a replayed stream may carry slot-C (fault) events: drop their writes
+        ring = _leaf_ring(w0, snapshot_dtype, drop=True)
+        master = default_update and ring.master is not None
         update_step = _make_update_step(
-            grad_fn, fedbuff_Z, update_fn, pack, unpack, flat_mode, enc, guard
+            grad_fn, fedbuff_Z, update_fn, ring, master, guard
         )
 
         def body(carry, xs):
@@ -743,9 +857,7 @@ def _make_host_runner(
             ks = k0 + jnp.arange(Jc.shape[0], dtype=Jc.dtype)
             return jax.lax.scan(body, carry, (Jc, slotc, scalec, ks), unroll=unroll)[0]
 
-        carry, to_tree = _init_update_carry(
-            w0, C, pack, unpack, flat_mode, fedbuff_Z, enc
-        )
+        carry, to_tree = _init_update_carry(w0, C, ring, master, fedbuff_Z)
         T = int(J.shape[0])
         if eval_fn is not None and eval_every and T >= eval_every:
             n_chunks = T // eval_every
@@ -837,8 +949,8 @@ def _make_host_block_runner(
     group boundaries land on exact event multiples), trailing rows replay
     flat.
 
-    The blocked engine requires the flat-packed snapshot codec (uniform
-    parameter dtype) and the default linear update; ``kernel`` picks the
+    The blocked engine requires the flat-packed snapshot ring (all-float
+    parameters) and the default linear update; ``kernel`` picks the
     jnp fallback ("jnp", the CPU/parity path) or the fused Pallas kernel
     ("pallas") for the prefix-scan + scatter.
 
@@ -871,18 +983,11 @@ def _make_host_block_runner(
         pad_to = BLOCK_TILE
 
     def run_local(w0, J, slot, scale, k, mask, chunk_blocks=0, n_chunks=0):
-        pack, unpack, enc = _snapshot_codec(w0, snapshot_dtype, pad_to=pad_to)
-        if unpack is None:
-            raise ValueError(
-                "block_size > 1 requires all-float parameters "
-                "(flat-packed snapshot storage)"
-            )
+        ring = _flat_ring(w0, snapshot_dtype, pad_to=pad_to)
         block_step = _make_block_step(
-            grad_fn, fedbuff_Z, pack, unpack, kernel, lane_axis, guard,
+            grad_fn, fedbuff_Z, ring, kernel, lane_axis, guard,
         )
-        carry, to_tree = _init_update_carry(
-            w0, C + 1, pack, unpack, True, fedbuff_Z, enc
-        )
+        carry, to_tree = _init_update_carry(w0, C + 1, ring, True, fedbuff_Z)
 
         def body(c, xs):
             return block_step(c, *xs), ()
@@ -968,7 +1073,7 @@ def _make_host_block_runner(
 # device stream: fused generator + control loop
 # ------------------------------------------------------------------ #
 def _make_fused_advance(
-    grad_fn, n, C, E, update_step, pack, unpack, enc, fedbuff_Z, guard, *,
+    grad_fn, n, C, E, update_step, ring, fedbuff_Z, guard, *,
     importance, faulty, guard_stale, need_stats, axis, lane_devices, unroll,
     classes=None, serving=None, scenario=False,
 ):
@@ -1038,7 +1143,7 @@ def _make_fused_advance(
             every scatter drops — exactly the fault-masking idiom
             (`KIND_CRASH` etc.) — while on train events `serve_apply`
             masks to a no-op via ``live=is_ext``.  No `lax.cond`: routing
-            the (C, P) snapshot ring through a cond output forces XLA to
+            the snapshot ring through a cond output forces XLA to
             re-materialize the ring every step instead of aliasing it
             through the scan carry (measured ~1.6x wall), and even a
             small-state cond pays per-step buffer marshaling that exceeds
@@ -1221,7 +1326,7 @@ def _make_fused_advance(
             else:
                 jv, sv, scv, confv, tv = sys_
             snaps = ucarry[1]
-            batched_grads = _make_batched_grads(grad_fn, pack, unpack)
+            batched_grads = _make_batched_grads(grad_fn, ring)
             if axis is None:
                 with jax.named_scope(obs.RING):
                     rows = snaps[sv]
@@ -1239,7 +1344,7 @@ def _make_fused_advance(
                 with jax.named_scope(obs.GRAD):
                     G0 = jax.lax.all_gather(Gl, axis, tiled=True)
 
-            apply_event = _make_apply_event(fedbuff_Z, enc, guard)
+            apply_event = _make_apply_event(fedbuff_Z, ring, guard)
 
             def fbody(cc, xx):
                 if guard_stale:
@@ -1252,7 +1357,7 @@ def _make_fused_advance(
                 with jax.named_scope(obs.GRAD):
                     g = jax.lax.cond(
                         conf >= 0,
-                        lambda r: pack(grad_fn(j, unpack(r), k)),
+                        lambda r: ring.master(grad_fn(j, ring.to_tree(r), k)),
                         lambda r: g0,
                         row,
                     )
@@ -1407,7 +1512,7 @@ def make_fused_runner(
     ring at the known-good (last-accepted-update) row, deadline-timed-out
     with capped jittered exponential-backoff retries, and shed under
     overload — the resilient serving plane of `core.serving`.  Requires
-    ``block_size=1``, the dense stream, flat-packed parameters and no
+    ``block_size=1``, the dense stream, all-float parameters and no
     FedBuff; composes with faults, guards and the adaptive controller.
     Serve events skip the gradient entirely (a `lax.cond` branch,
     un-vmapped) and are invisible to the training-side scatters (slot C /
@@ -1536,25 +1641,22 @@ def make_fused_runner(
     update_fn, default_update = _default_update(update_fn)
 
     def run(w0, mu, p0, key, eta):
-        pack, unpack, enc = _snapshot_codec(w0, snapshot_dtype)
-        flat_mode = default_update and unpack is not None
-        if E > 1 and not flat_mode:
-            raise ValueError(
-                "block_size > 1 requires all-float parameters "
-                "(flat-packed snapshot storage)"
+        if E > 1:
+            ring, rows = _flat_ring(w0, snapshot_dtype), C + 1
+        else:
+            ring = _leaf_ring(
+                w0, snapshot_dtype, drop=faulty or serving_on or scen_on
             )
-        if serving_on and not flat_mode:
-            raise ValueError(
-                "serving= requires all-float parameters (the serving read "
-                "path gathers flat-packed snapshot rows)"
-            )
+            rows = C
+        master = default_update and ring.master is not None
+        if serving_on and not master:
+            raise ValueError("serving= requires all-float parameters")
         update_step = _make_update_step(
-            grad_fn, fedbuff_Z, update_fn, pack, unpack, flat_mode, enc, guard
+            grad_fn, fedbuff_Z, update_fn, ring, master, guard
         )
-        rows = C + 1 if E > 1 else C
         with jax.named_scope(obs.RING):
             ucarry, to_tree = _init_update_carry(
-                w0, rows, pack, unpack, flat_mode, fedbuff_Z, enc
+                w0, rows, ring, master, fedbuff_Z
             )
 
         mu = jnp.asarray(mu, jnp.float32)
@@ -1603,7 +1705,7 @@ def make_fused_runner(
             slot_scale0 = jnp.broadcast_to(eta, (C,))
 
         advance = _make_fused_advance(
-            grad_fn, n, C, E, update_step, pack, unpack, enc, fedbuff_Z, guard,
+            grad_fn, n, C, E, update_step, ring, fedbuff_Z, guard,
             importance=importance, faulty=faulty, guard_stale=guard_stale,
             need_stats=need_stats, axis=axis, lane_devices=lane_devices,
             unroll=unroll, classes=classes, serving=serving,

@@ -25,8 +25,8 @@ Robustness envelope (`ServingConfig`):
     2**attempt, backoff_cap)`` — the exponential holding time *is* the
     jitter — or is evicted and counted ``timed_out``.
   * **degraded-mode snapshot serving** — requests are answered from the
-    engine's ``(C, P)`` snapshot ring at the **known-good pointer**: the
-    ring row written by the most recent *accepted* update.  Divergence-
+    engine's snapshot ring (one ``(C, *leaf.shape)`` buffer per parameter
+    leaf) at the **known-good pointer**: the ring row written by the most recent *accepted* update.  Divergence-
     guard-rejected and fault-masked updates never advance the pointer (and
     never enter ``w`` at all), so a guard trip degrades the served model to
     the last known-good iterate instead of serving poison; the per-serve
@@ -294,13 +294,28 @@ def hist_bucket(x, lo: int = HIST_LO):
     return jnp.clip(b, 0, HIST_BUCKETS - 1)
 
 
+def _row_mean(snaps, s):
+    """Mean of ring row ``s`` over every parameter, in float32: the sum
+    over the ring's leaves divided by their total size P."""
+    import jax
+    import jax.numpy as jnp
+
+    leaves = jax.tree_util.tree_leaves(snaps)
+    total = sum(
+        jnp.sum(jax.lax.dynamic_index_in_dim(b, s, 0, keepdims=False)
+                .astype(jnp.float32))
+        for b in leaves
+    )
+    return total / sum(int(np.prod(b.shape[1:])) for b in leaves)
+
+
 def serve_apply(cfg: ServingConfig, sv: ServeState, stats: ServeStats,
                 u, t, k, snaps, live=True):
     """Resolve one serving event: which clock fired, and its transition.
 
     ``u`` is the conditional uniform from the merged race (exact in law),
     ``t`` the post-event clock, ``k`` the server step, ``snaps`` the
-    ``(C, P)`` snapshot ring.  Everything is masked scatters — no data-
+    snapshot ring (a pytree of ``(C, ...)`` buffers).  Everything is masked scatters — no data-
     dependent control flow — so the engine runs it *unconditionally* every
     merged step with ``live = is_ext``: when ``live`` is False every
     transition flag masks off and the call is an exact no-op on ``sv`` /
@@ -359,14 +374,14 @@ def serve_apply(cfg: ServingConfig, sv: ServeState, stats: ServeStats,
     ).astype(jnp.int32)
     sj = t - sv.t_arr[i_head]
     # the read path: answer from the known-good snapshot row.  The row
-    # mean is the servable observable — a guard-rejected (non-finite /
+    # mean (over all P parameters, in float32) is the servable observable — a guard-rejected (non-finite /
     # exploded) update reaching it would poison this checksum, which the
     # never-served property test asserts stays finite.  Scalar-output
     # cond: the O(P) row reduction runs only when a serve completes, and
     # `snaps` stays a read-only closure capture (no buffer marshaling).
     row_mean = jax.lax.cond(
         is_srv,
-        lambda: jnp.mean(snaps[sv.kg_slot].astype(jnp.float32)),
+        lambda: _row_mean(snaps, sv.kg_slot),
         lambda: jnp.float32(0.0),
     )
     staleness = (k - sv.kg_step).astype(jnp.float32)

@@ -234,6 +234,38 @@ def test_resume_fingerprint_mismatch_raises(tmp_path):
             guard=GuardConfig(max_grad_norm=99.0), resume=True, **kwargs)
 
 
+def _write_flat_ring_checkpoint(d, step=50):
+    """A checkpoint as the per-event checkpointed runs wrote it while the
+    snapshot ring was one flat (C, P) array: a packed master vector, the
+    (C, P) ring, and no ring layout in its metadata."""
+    P = sum(int(np.size(x)) for x in jax.tree_util.tree_leaves(_W0))
+    carry = (np.zeros(P, np.float32), np.zeros((_C, P), np.float32), (),
+             np.zeros(2, np.int32))
+    ck.save(d, step, {"carry": carry, "evals": np.full(8, np.nan, np.float32),
+                      "cursor": np.int64(step)},
+            metadata={"fingerprint": "0" * 40, "events_done": step})
+
+
+@pytest.mark.parametrize("path", ["fused", "host"])
+def test_resume_refuses_flat_ring_checkpoint(tmp_path, path):
+    d = str(tmp_path / path)
+    _write_flat_ring_checkpoint(d)
+    if path == "fused":
+        run = lambda: run_checkpointed(
+            _grad, _N, _C, _T, w0=_W0, mu=_MU, p0=_P,
+            key=jax.random.PRNGKey(3), eta=0.05, ckpt_dir=d, ckpt_every=50,
+            eval_fn=_loss, eval_every=25, resume=True,
+        )
+    else:
+        _, stream, scale = _host_arrays()
+        run = lambda: run_checkpointed_host(
+            _grad, _C, _W0, stream.J, stream.slot, scale, ckpt_dir=d,
+            ckpt_every=50, eval_fn=_loss, eval_every=25, resume=True,
+        )
+    with pytest.raises(ValueError, match=r"one flat \(C, P\) array"):
+        run()
+
+
 def test_run_experiment_resume_bitwise(tmp_path):
     from repro.configs.base import FLConfig
     from repro.fl.engine import run_experiment

@@ -11,6 +11,7 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler library.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -19,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.engine_scan import make_fused_runner
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.ssd_scan import ssd_scan
@@ -48,7 +50,7 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_for_chip(fn, *specs):
+def _compiled(fn, *specs):
     # a compile for a described device lands in the persistent cache but can
     # never be read back without the chip — keep the cache out of it
     from jax.experimental.compilation_cache import compilation_cache
@@ -56,10 +58,14 @@ def _compile_for_chip(fn, *specs):
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        hlo = jax.jit(fn).lower(*specs).compile().as_text()
+        return jax.jit(fn).lower(*specs).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
         compilation_cache.reset_cache()
+
+
+def _compile_for_chip(fn, *specs):
+    hlo = _compiled(fn, *specs).as_text()
     assert "tpu_custom_call" in hlo
     return hlo
 
@@ -163,3 +169,60 @@ def test_block_kernels_mamba2_130m_ring(one_chip, kernel):
         _spec((E, P), jnp.float32, one_chip),
         _spec((E,), jnp.int32, one_chip),
     )
+
+
+def test_snapshot_ring_per_leaf_mamba2_130m(one_chip):
+    # the per-event fused runner over Mamba2-130M's mixed bf16/fp32 tree,
+    # cut to 2 layers at the published widths; a stand-in gradient touches
+    # every leaf (the model step is not what this test compiles)
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.models.module import abstract_params
+
+    n, C, T = 100, 10, 8
+    tree = abstract_params(
+        api.model_meta(get_config("mamba2-130m").replace(num_layers=2))
+    )
+    leaves = jax.tree_util.tree_leaves(tree)
+    assert {l.dtype for l in leaves} == {jnp.dtype(jnp.bfloat16),
+                                         jnp.dtype(jnp.float32)}
+    P = sum(int(np.prod(l.shape)) for l in leaves)
+
+    def grad(j, w, k):
+        f = 1.0 + j.astype(jnp.float32) * 1e-3 + k.astype(jnp.float32) * 1e-4
+        return jax.tree_util.tree_map(
+            lambda x: (x.astype(jnp.float32) * f).astype(x.dtype), w
+        )
+
+    def eval_fn(w):
+        return sum(jnp.sum(x.astype(jnp.float32))
+                   for x in jax.tree_util.tree_leaves(w))
+
+    run = make_fused_runner(grad, n, C, T, eval_fn=eval_fn, eval_every=T)
+    compiled = _compiled(
+        run,
+        jax.tree_util.tree_map(
+            lambda x: _spec(x.shape, x.dtype, one_chip), tree
+        ),
+        _spec((n,), jnp.float32, one_chip),
+        _spec((n,), jnp.float32, one_chip),
+        _spec((2,), jnp.uint32, one_chip),
+        _spec((), jnp.float32, one_chip),
+    )
+    hlo = compiled.as_text()
+    # no flat ring, no packed gradient or row
+    assert f"[{C},{P}]" not in hlo and f"[{P}]" not in hlo
+    # each leaf's ring buffer: C leads, outside the two tiled minor dims
+    layouts = {}
+    for dims, mtm in re.findall(r"\[(\d+(?:,\d+)+)\]\{([\d,]+)", hlo):
+        layouts.setdefault(tuple(map(int, dims.split(","))), set()).add(mtm)
+    for l in leaves:
+        row = (1,) * (2 - len(l.shape)) + tuple(l.shape)
+        found = layouts.get((C,) + row)
+        assert found, f"no ring buffer of shape {(C,) + row}"
+        for mtm in found:
+            order = [int(d) for d in mtm.split(",")]
+            assert order[-1] == 0 and 0 not in order[:2], (row, mtm)
+    # the flat ring alone, C padded to the tile's 8 rows, in float32
+    flat_ring_bytes = -(-C // 8) * 8 * P * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < flat_ring_bytes
