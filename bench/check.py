@@ -6,10 +6,10 @@ the window's own calls on the window's own runner.  After the window the
 reference follows those calls: from the same starting weights, along the
 same events (checked one by one by `bench.race`), on the same batches, it
 applies Algorithm 1 in float32 at matmul precision "highest" -- a float32
-master copy, a ring of C dispatch-time snapshots, ``w -= eta / (n p_J) *
-grad`` at the completing task's snapshot -- and hands the next call its
-weights in the dtypes the configuration stores them in, as the program's
-engine does.  It evaluates the cell's eval loss at the end of each call.
+master copy, a ring of C dispatch-time snapshots kept in host memory,
+``w -= eta / (n p_J) * grad`` at the completing task's snapshot -- and
+hands the next call its weights in the dtypes the configuration stores them
+in, as the program's engine does.  It evaluates the cell's eval loss at the end of each call.
 
 Numbers a cell may compare, each with its limit from the cell's traffic
 file (the traffic file names those it compares; `numbers` has the rest):
@@ -59,62 +59,99 @@ def batch_starts(data_seed: int, shard_rows: int, batch: int) -> np.ndarray:
 
 
 class Reference:
-    """The reference of one cell: Algorithm 1 over a ring of snapshots, one
-    compiled scan over a call's events per precision."""
+    """The reference of one cell: Algorithm 1 event by event, one compiled
+    step per precision, the ring of C snapshots in host memory.
+
+    On the device the replay holds the master copy, one snapshot and its
+    gradient (plus the step's activations), whatever C is; the host holds
+    the distinct snapshots that later events of the call still read, at most
+    ``min(C, T + 1)`` copies of the weights in the master dtype, and the
+    weights handed on after each call."""
 
     def __init__(self, model, shards, eval_batch, batch: int, starts, C: int, half_batch=False):
         self.model, self.shards, self.ev, self.C = model, shards, eval_batch, C
         self.starts = np.asarray(starts)
         self.rows = batch // 2 if half_batch else batch
-        self._call, self._eval = {}, {}
+        self._fn = {}
 
-    def _fns(self, precision: str):
-        if precision not in self._call:
-            model, rows, C = self.model, self.rows, self.C
+    def _fns(self, precision: str, w_in):
+        """``(step, cast, store, eval)`` of one precision; the step is compiled
+        once, for the tree of ``w_in`` in the master dtype."""
+        if precision not in self._fn:
+            model, rows = self.model, self.rows
             master = model.master_dtype(precision)
+            tmap = jax.tree_util.tree_map
 
-            def window(shards, j, st):
+            def step(w, snap, shards, j, st, sc):
                 def cut(a):
                     idx = (j, st) + (0,) * (a.ndim - 2)
                     return jax.lax.dynamic_slice(a, idx, (1, rows) + a.shape[2:])[0]
-                return {k: cut(v) for k, v in shards.items()}
+                batch = {k: cut(v) for k, v in shards.items()}
+                g = jax.grad(lambda ww: model.ref_loss(ww, batch, precision))(snap)
+                return tmap(lambda a, b: (a - sc * b.astype(a.dtype)).astype(a.dtype), w, g)
 
-            def call(w_in, shards, J, slot, start, scale):
-                w = jax.tree_util.tree_map(lambda a: a.astype(master), w_in)
-                ring = jax.tree_util.tree_map(lambda a: jnp.broadcast_to(a, (C,) + a.shape), w)
+            cast = jax.jit(lambda t: tmap(lambda a: a.astype(master), t))
+            w = jax.eval_shape(cast, w_in)
+            scalar = lambda d: jax.ShapeDtypeStruct((), d)
+            compiled = jax.jit(step, donate_argnums=0).lower(
+                w, w, self.shards, scalar(jnp.int32), scalar(jnp.int32), scalar(jnp.float32)).compile()
+            dtypes = tmap(lambda a: a.dtype, w_in)
+            store = jax.jit(lambda w: tmap(
+                lambda a, d: model.store(a, precision).astype(d), w, dtypes))
+            ev = jax.jit(lambda w, ev: model.ref_loss(w, ev, precision))
+            self._fn[precision] = (compiled, cast, store, ev)
+        return self._fn[precision]
 
-                def event(carry, ev):
-                    w, ring = carry
-                    j, s, st, sc = ev
-                    snap = jax.tree_util.tree_map(lambda r: r[s], ring)
-                    g = jax.grad(lambda ww: model.ref_loss(ww, window(shards, j, st), precision))(snap)
-                    w = jax.tree_util.tree_map(
-                        lambda a, b: (a - sc * b.astype(a.dtype)).astype(a.dtype), w, g)
-                    ring = jax.tree_util.tree_map(lambda r, a: r.at[s].set(a), ring, w)
-                    return (w, ring), None
-
-                (w, _), _ = jax.lax.scan(event, (w, ring), (J, slot, start, scale))
-                return jax.tree_util.tree_map(lambda a, b: model.store(a, precision).astype(b.dtype),
-                                              w, w_in)
-
-            self._call[precision] = jax.jit(call)
-            self._eval[precision] = jax.jit(lambda w, ev: model.ref_loss(w, ev, precision))
-        return self._call[precision], self._eval[precision]
+    def step_bytes(self, precision: str = "float32") -> dict:
+        """Device bytes of the compiled event step (compiled by `replay`):
+        arguments, outputs, the outputs that alias a donated argument,
+        temporaries, and what the step holds in all."""
+        m = self._fn[precision][0].memory_analysis()
+        parts = {"argument": m.argument_size_in_bytes, "output": m.output_size_in_bytes,
+                 "alias": m.alias_size_in_bytes, "temp": m.temp_size_in_bytes}
+        parts["total"] = parts["argument"] + parts["output"] - parts["alias"] + parts["temp"]
+        return {k: int(v) for k, v in parts.items()}
 
     def replay(self, w0, calls, precision: str = "float32"):
         """Follow ``calls`` (a list of `race.Events`) from ``w0``.  Returns the
-        weights handed on after each call and the eval loss there."""
-        call, ev = self._fns(precision)
+        weights handed on after each call, in host memory, and the eval loss
+        there."""
         outs, losses = [], []
-        w = w0
         with jax.default_matmul_precision("highest"):
+            step, cast, store, ev = self._fns(precision, w0)
             for e in calls:
-                w = call(w, self.shards, jnp.asarray(e.J, jnp.int32), jnp.asarray(e.slot, jnp.int32),
-                         jnp.asarray(self.starts[e.k % OFFSET_BLOCK], jnp.int32),
-                         jnp.asarray(e.scale, jnp.float32))
-                outs.append(w)
+                w = store(self._call(step, cast(outs[-1] if outs else w0), e))
                 losses.append(float(ev(w, self.ev)))
+                outs.append(jax.device_get(w))
+                del w
         return outs, losses
+
+    def _call(self, step, w, e):
+        """One call's events from the master copy ``w`` (donated).  Version v
+        of the weights is ``w`` after v events; every slot starts at version
+        0, and event k reads the version its slot last took and writes
+        version k + 1 into it.  A version goes to the host only where an
+        event reads it, and leaves it after its last read."""
+        slot = np.asarray(e.slot)
+        ver, src = [0] * self.C, []
+        for k, s in enumerate(slot):
+            src.append(ver[s])
+            ver[s] = k + 1
+        last = {v: k for k, v in enumerate(src)}
+        host = {0: jax.device_get(w)}
+        J, sc = np.asarray(e.J, np.int32), np.asarray(e.scale, np.float32)
+        st = self.starts[np.asarray(e.k) % OFFSET_BLOCK].astype(np.int32)
+        for k, v in enumerate(src):
+            snap = jax.device_put(host[v])
+            # waited for, so that no later snapshot is uploaded while this
+            # one is held: one snapshot on the device at a time
+            w = jax.block_until_ready(step(w, snap, self.shards, J[k], st[k], sc[k]))
+            del snap
+            if k + 1 in last:
+                host[k + 1] = jax.device_get(w)
+            for u in [u for u in host if last[u] == k]:
+                del host[u]
+        return w
 
 
 @jax.jit

@@ -389,7 +389,8 @@ def compare(cell_: Cell, checked: list, release: bool = True) -> tuple[dict, dic
     units = chk.leaf_units(w0)
     values = chk.numbers(prog_n1, prog_nN, prog_loss, ref_n1, ref_nN, ref_loss, units)
     values["race_faults"] = float(faults)
-    notes = {"reference_s": time.perf_counter() - t0, "ties_followed": ties,
+    notes = {"reference_s": time.perf_counter() - t0, "reference_step_bytes": ref.step_bytes(),
+             "ties_followed": ties,
              "race_faults_at": where[:8],
              "program_losses": prog_loss, "reference_losses": ref_loss,
              "leaf_norms": [[name, *map(float, v)] for name, *v in zip(
